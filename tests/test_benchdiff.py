@@ -65,7 +65,7 @@ def test_baseline_missing_and_current_missing_metrics():
 
 
 def test_roundfile_tail_embedding_parses():
-  rec = load_bench(REPO / "BENCH_r05.json")
+  rec = load_bench(REPO / "BENCH_r03.json")
   assert rec is not None
   assert metrics_of(rec).get("tok_s") is not None
 
@@ -116,9 +116,7 @@ def test_gate_flags_bad_files(tmp_path):
 
 def test_gate_rejects_modern_record_missing_implausible(tmp_path):
   """Omitting the `implausible` key entirely must not bypass the physics
-  checks — only the frozen pre-gate history names may omit it. (The one
-  committed rider, BENCH_r02.json's lying-backend evidence, is covered by
-  the whole-repo gate test above.)"""
+  checks: no committed record may omit it."""
   (tmp_path / "BENCH_TPU_r99.json").write_text(json.dumps({
     "metric": "decode_tok_s_x_bf16_1chip", "tok_s": 50000.0, "platform": "tpu",
     "hbm_bw_pct": 14000.0,  # over-roofline, and no `implausible` key at all

@@ -306,34 +306,25 @@ def test_fabric_probe_retry_exhaustion_counts_one_error():
 
 # ------------------------------------------------------- compile-cache wiring
 
-def test_engine_wires_persistent_compile_cache_once(tmp_path, monkeypatch):
+def test_engine_wires_persistent_compile_cache_once(monkeypatch):
+  """The engine's jax accessor turns the persistent cache on through the one
+  helper (utils/compile_cache) — and the helper applies its config once per
+  process, so an engine built inside the suite never resets the suite's
+  own threshold."""
   jax = pytest.importorskip("jax")
-  monkeypatch.setenv("XOT_COMPILE_CACHE_DIR", str(tmp_path / "xla-cache"))
   from xotorch_tpu.inference.jax_engine.engine import JAXShardInferenceEngine
-  from xotorch_tpu.utils import knobs
-  # __new__ + the two knob attrs: the wiring under test is exactly what
-  # __init__ seeds, without dragging a full engine (mesh, weights) along.
+  from xotorch_tpu.utils import compile_cache
   engine = JAXShardInferenceEngine.__new__(JAXShardInferenceEngine)
-  engine._compile_cache_dir = knobs.get_str("XOT_COMPILE_CACHE_DIR")
-  engine._compile_cache_wired = False
-  saved = {opt: getattr(jax.config, opt, None)
-           for opt in ("jax_compilation_cache_dir",
-                       "jax_persistent_cache_min_compile_time_secs")}
-  try:
-    assert engine._jax() is jax
-    assert engine._compile_cache_wired is True
-    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "xla-cache")
-    # Idempotent: the second call never re-applies the config.
-    monkeypatch.setattr(jax.config, "update",
-                        lambda *a, **k: pytest.fail("re-wired"))
-    assert engine._jax() is jax
-  finally:
-    monkeypatch.undo()  # restore jax.config.update before using it
-    for opt, val in saved.items():
-      try:
-        jax.config.update(opt, val)
-      except (AttributeError, ValueError):
-        pass
+  monkeypatch.setattr(compile_cache, "_enabled_dir", None)
+  updates = []
+  monkeypatch.setattr(jax.config, "update", lambda opt, val: updates.append(opt))
+  assert engine._jax() is jax
+  assert compile_cache._enabled_dir == compile_cache.cache_dir()
+  assert "jax_persistent_cache_min_compile_time_secs" in updates
+  # Idempotent: the second call never re-applies the config.
+  del updates[:]
+  assert engine._jax() is jax
+  assert updates == []
 
 
 # --------------------------------------------------- admission queue high-water

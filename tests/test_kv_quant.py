@@ -132,7 +132,7 @@ async def test_kv_quant_with_prefix_cache(tmp_path, monkeypatch):
 
 async def test_kv_quant_flash_decode_matches_xla_path(tmp_path, monkeypatch):
   """int8 KV caches now TAKE the Pallas cached kernel (in-kernel per-tile
-  dequant, ops/flash_decode._load_kv): the engine must select it and the
+  dequant, ops/flash_decode._scores): the engine must select it and the
   logits must match the XLA dense path on the SAME quantized cache — the
   dequant math is identical, only the attention implementation differs."""
   import numpy as np

@@ -4,7 +4,7 @@ Each ring partition is a true tensor-parallel mesh stage: partition weights
 shard per parallel/mesh.spec_for_param, the paged arena and contiguous
 caches shard their Hkv axis (cache_spec), activations pin the Megatron
 layout (transformer._tp_constraint), and the paged Pallas kernels run
-per-tp-shard (ops/paged_attention._tp_sharded_call). The acceptance bars
+per-tp-shard (parallel/mesh.per_shard_kernel). The acceptance bars
 tested here, on the virtual 8-device CPU mesh from conftest:
 
 - greedy streams under XOT_TP=2 (and an infeasible request clamped down)

@@ -17,7 +17,7 @@ Correctness bars, all against the contiguous default path:
   construction), and refcounts drain to zero.
 
 The 16k-member mixed batch of the acceptance criterion runs on-chip via the
-bench `paged` stage (scripts/tpu_retry.py); here the same invariants run at
+bench `paged` stage (bench.py); here the same invariants run at
 CPU-sized lengths (page 16, prompts 40/3/4 growing past their po2 buckets).
 """
 import asyncio

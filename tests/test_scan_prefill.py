@@ -2,8 +2,7 @@
 
 Round 3 measured 16 k prefill at ~7% MFU; a large share was structural —
 the host-side segment loop pays one dispatch + one H2D round-trip per
-segment (engine._infer_sync / the bench's long stage), which on a
-tunneled/remote device rivals the segment compute. prefill_scan folds the
+segment (engine._infer_sync / the bench's long stage). prefill_scan folds the
 whole segment loop into ONE `lax.scan` executable over the occupancy-aware
 cached-attention kernel (in-segment causality is by absolute position, so
 the same kernel serves the from-zero segment and every later one).

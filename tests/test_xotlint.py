@@ -859,7 +859,10 @@ async def test_dynamic_sync_callers_agree_with_sanctioned_list(monkeypatch):
   def _record(kind):
     f = sys._getframe(2)
     if f.f_code.co_filename.endswith("jax_engine/engine.py"):
-      callers.add((getattr(f.f_code, "co_qualname", f.f_code.co_name), kind))
+      # The bare function name: co_qualname carries the class ("Engine.method",
+      # "outer.<locals>.inner"), and the static sets below are compared by
+      # their final component.
+      callers.add((f.f_code.co_name, kind))
 
   def counting_asarray(*a, **kw):
     _record("np.asarray")

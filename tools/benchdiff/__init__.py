@@ -47,7 +47,7 @@ CONFIG_KEYS = frozenset({
 NOISE = {
   "tok_s": 0.05,
   "value": 0.05,
-  "ttft_ms": 0.15,  # TTFT through the tunnel jitters hard run to run
+  "ttft_ms": 0.15,  # one request's TTFT on the host clock jitters hard run to run
   "per_token_ms": 0.05,
   "long_tok_s": 0.07,
   "long_prefill_s": 0.10,
@@ -369,12 +369,6 @@ def render_markdown(rows: List[Dict[str, Any]], title: str = "") -> str:
 # ----------------------------------------------------------------- CI gate
 
 
-# The only committed harvests measured before bench.py carried the
-# plausibility verdict (the round-2 lying-backend artifact is kept as
-# evidence, PERF.md "Measurement integrity"). Frozen by NAME so a new file
-# cannot ride the exemption by simply omitting the field.
-_PRE_GATE_FILES = frozenset({"BENCH_r02.json"})
-
 
 def _plausibility_findings(name: str, rec: Dict[str, Any]) -> List[str]:
   """The measurement-integrity rules bench.py enforces live, re-applied to
@@ -382,13 +376,9 @@ def _plausibility_findings(name: str, rec: Dict[str, Any]) -> List[str]:
   the tree claiming over-roofline physics without its `implausible` flag."""
   findings = []
   if "implausible" not in rec:
-    if name in _PRE_GATE_FILES:
-      return findings
-    # Every emit since the gate landed includes the field; a modern record
-    # without it is a finding on its own, and the physics checks below
-    # still run against it (flagged=False).
-    findings.append(f"{name}: record carries no `implausible` verdict "
-                    "(only the pre-gate history files may omit it)")
+    # Every emit includes the field; a record without it is a finding on its
+    # own, and the physics checks below still run against it (flagged=False).
+    findings.append(f"{name}: record carries no `implausible` verdict")
   flagged = bool(rec.get("implausible"))
   checks = (
     ("hbm_bw_pct", 110.0, "exceeds the physical HBM ceiling"),

@@ -305,12 +305,6 @@ class SoakRing:
                **self.cfg.alert_env}
       if self.cfg.router:
         extra.update(self.cfg.replica_env)
-      if self.cfg.fleet:
-        # Persistent jit cache: the template slots carry the same knob, so
-        # a controller respawn lands on compiles this very warmup paid —
-        # the "warm cold-start" the fleet smoke soft-verifies.
-        extra["XOT_COMPILE_CACHE_DIR"] = os.environ.get(
-          "JAX_COMPILATION_CACHE_DIR", "/root/.cache/xot_jax_cache")
       if self.cfg.fabric:
         # Disaggregated roles: replica 0 prefills and offers, the rest
         # decode. Peers are cross-wired so an entry fetch resolves by URL

@@ -17,8 +17,8 @@ This package closes the loop the metrics already make possible:
   file so a NEW lease holder can retire processes a dead holder spawned.
 - **`FleetController`** (controller.py): one tick per router poll. Dead
   detection (the unreachable/scrape-failure streak), crash respawn down
-  the warm cold-start path (persistent XLA compile cache via
-  XOT_COMPILE_CACHE_DIR + PRESERVE-style prefix pre-announce before the
+  the warm cold-start path (the persistent XLA compile cache of
+  utils/compile_cache + PRESERVE-style prefix pre-announce before the
   replica enters rotation), scale-up on sustained admission-queue
   pressure, and scale-down of controller-added spares through the
   existing drain lifecycle so no in-flight request dies.

@@ -7,6 +7,14 @@ JSON next to the template (atomic replace, same shared-host discipline as
 the actuation lease). Liveness is NOT judged here: the router's poll loop
 owns reachability; the spawner only answers "did the process I started
 exit" for boot-failure attribution.
+
+One process per chip: the router that owns this spawner never touches JAX,
+so it holds no accelerator a replica could inherit. A replica claims what
+its OWN environment shows it — the slot's `env` block is where a template
+that packs several replicas onto one host gives each its own device (the
+TPU runtime's visible-chips variables, or JAX_PLATFORMS=cpu as the soak's
+templates do). Two slots that name the same chip fail or hang at backend
+init; nothing here arbitrates that.
 """
 from __future__ import annotations
 

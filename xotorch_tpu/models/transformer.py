@@ -49,13 +49,18 @@ def _maybe_lora(layer: Params, slot: str, h: jnp.ndarray, base_out: jnp.ndarray)
   return base_out + delta.astype(base_out.dtype) * LORA_SCALE
 
 
-def _linear(layer: Params, slot: str, h: jnp.ndarray) -> jnp.ndarray:
+def _linear(layer: Params, slot: str, h: jnp.ndarray, tp_mesh=None) -> jnp.ndarray:
   """h @ layer[slot], transparently dequantizing weight-only-quantized slots
   (models/quantize.py): presence of `<slot>_scale` (int8, per-out-channel)
   or `<slot>_gscale` (int4, group-wise) is a static pytree property, so the
   quantized graph is baked at trace time. XLA fuses the narrow->bf16 convert
   + scale into the dot's operand read — HBM streams int8/int4, the MXU
-  computes bf16."""
+  computes bf16.
+
+  `tp_mesh` (static): under a serving mesh the decode matvec Pallas kernels
+  stand down — GSPMD has no partitioning rule for the custom call (the
+  chip's compiler refuses it inside a multi-device jit), where the einsum
+  forms below partition into per-shard partial dots."""
   w = layer[slot]
   gscale = layer.get(slot + "_gscale")
   if gscale is not None:
@@ -63,17 +68,15 @@ def _linear(layer: Params, slot: str, h: jnp.ndarray) -> jnp.ndarray:
     # byte — models/quantize.pack_int4), gscale [G, out].
     B, T, _ = h.shape
     k4 = knobs.get_str("XOT_INT4_KERNEL")
-    if B * T <= 8 and (k4 == "force" or (k4 != "0" and jax.default_backend() == "tpu")):
+    if (B * T <= 8 and tp_mesh is None
+        and (k4 == "force" or (k4 != "0" and jax.default_backend() == "tpu"))):
       # Decode hot path ON REAL TPU: Pallas kernel (ops/int4_matmul.py)
       # unpacks the nibbles IN REGISTERS between the packed-tile read and
       # the MXU dot, so HBM streams the promised 0.5 bytes/param — XLA's
       # lowering of the unpack graph materializes the unpacked tensor,
       # erasing the format's bandwidth win (measured 230 -> 275 tok/s).
       # Off-TPU the kernel would run in interpret mode (far slower than
-      # the einsum below); the engine also sets XOT_INT4_KERNEL=0 when
-      # serving over a tp mesh — GSPMD has no partitioning rule for the
-      # custom call, so it would gather the full weight per step where the
-      # einsum partitions into per-shard partial dots.
+      # the einsum below).
       from xotorch_tpu.ops.int4_matmul import int4_grouped_matmul
       out = int4_grouped_matmul(h.reshape(B * T, h.shape[-1]), w, gscale)
       return out.reshape(B, T, -1).astype(h.dtype)
@@ -91,14 +94,13 @@ def _linear(layer: Params, slot: str, h: jnp.ndarray) -> jnp.ndarray:
     return h @ w
   B, T, _ = h.shape
   k8 = knobs.get_str("XOT_INT8_KERNEL")
-  if B * T <= 8 and (k8 == "force" or (k8 == "1" and jax.default_backend() == "tpu")):
+  if (B * T <= 8 and tp_mesh is None
+      and (k8 == "force" or (k8 == "1" and jax.default_backend() == "tpu"))):
     # Opt-in W8A8 decode path (ops/int8_matmul.py): the MXU consumes int8
     # weights directly (int32 accumulate) instead of the VPU running
     # convert+scale passes over every element first. Activations
     # row-quantize to int8 — approximate (~1/255), so the fused-dequant
     # path below stays the default; A/B'd on-chip via XOT_INT8_KERNEL.
-    # The engine clears the flag under a tp mesh (no GSPMD rule, same as
-    # the int4 kernel).
     from xotorch_tpu.ops.int8_matmul import int8_rowquant_matmul
     out = int8_rowquant_matmul(h.reshape(B * T, h.shape[-1]), w, scale)
     return out.reshape(B, T, -1).astype(h.dtype)
@@ -226,9 +228,9 @@ def _attention_block(
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
   B, T, H = x.shape
   h = rms_norm(x, layer["attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
-  q = _maybe_lora(layer, "wq", h, _linear(layer, "wq", h))
-  k = _maybe_lora(layer, "wk", h, _linear(layer, "wk", h))
-  v = _maybe_lora(layer, "wv", h, _linear(layer, "wv", h))
+  q = _maybe_lora(layer, "wq", h, _linear(layer, "wq", h, tp_mesh))
+  k = _maybe_lora(layer, "wk", h, _linear(layer, "wk", h, tp_mesh))
+  v = _maybe_lora(layer, "wv", h, _linear(layer, "wv", h, tp_mesh))
   if "bq" in layer:
     q = q + layer["bq"]
     k = k + layer["bk"]
@@ -320,7 +322,7 @@ def _attention_block(
         v_scale_pages=layer_cache.get("v_scale"))
     attn2d = _tp_constraint(
       attn.reshape(B, T, cfg.num_heads * cfg.head_dim), tp_mesh, 2)
-    out = _maybe_lora(layer, "wo", attn2d, _linear(layer, "wo", attn2d))
+    out = _maybe_lora(layer, "wo", attn2d, _linear(layer, "wo", attn2d, tp_mesh))
     if cfg.sandwich_norms:
       out = rms_norm(out, layer["post_attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
     return out, layer_cache
@@ -345,7 +347,7 @@ def _attention_block(
     # kernel, and out-of-window kv blocks are never DMA'd.
     from xotorch_tpu.ops.flash_attention import flash_attention
     attn = flash_attention(q, k, v, window=window, softcap=cfg.attn_logit_softcap,
-                           scale=attn_scale)
+                           scale=attn_scale, tp_mesh=tp_mesh)
   elif use_flash_decode:
     # Decode steps and chunked-prefill segments over a long resident cache:
     # Pallas kernel whose cost is proportional to the OCCUPIED prefix
@@ -368,7 +370,7 @@ def _attention_block(
                                   window=window, softcap=cfg.attn_logit_softcap,
                                   scale=attn_scale,
                                   k_scale=layer_cache.get("k_scale"),
-                                  v_scale=layer_cache.get("v_scale"))
+                                  v_scale=layer_cache.get("v_scale"), tp_mesh=tp_mesh)
   elif ring_mesh is not None:
     # Sequence-parallel training path (start_pos == 0, T sharded over 'sp'):
     # ring attention rotates KV chunks over ICI instead of materialising the
@@ -381,7 +383,7 @@ def _attention_block(
                          scale=attn_scale, softcap=cfg.attn_logit_softcap, window=window)
   attn2d = _tp_constraint(
     attn.reshape(B, T, cfg.num_heads * cfg.head_dim), tp_mesh, 2)
-  out = _maybe_lora(layer, "wo", attn2d, _linear(layer, "wo", attn2d))
+  out = _maybe_lora(layer, "wo", attn2d, _linear(layer, "wo", attn2d, tp_mesh))
   if cfg.sandwich_norms:
     out = rms_norm(out, layer["post_attn_norm"], cfg.rms_norm_eps, cfg.norm_offset)
   return out, layer_cache
@@ -390,10 +392,10 @@ def _attention_block(
 def _dense_mlp(layer: Params, h: jnp.ndarray, cfg: ModelConfig,
                tp_mesh=None) -> jnp.ndarray:
   gate = _mlp_act(cfg, _tp_constraint(
-    _maybe_lora(layer, "w_gate", h, _linear(layer, "w_gate", h)), tp_mesh, -1))
+    _maybe_lora(layer, "w_gate", h, _linear(layer, "w_gate", h, tp_mesh)), tp_mesh, -1))
   up = gate * _tp_constraint(
-    _maybe_lora(layer, "w_up", h, _linear(layer, "w_up", h)), tp_mesh, -1)
-  return _maybe_lora(layer, "w_down", up, _linear(layer, "w_down", up))
+    _maybe_lora(layer, "w_up", h, _linear(layer, "w_up", h, tp_mesh)), tp_mesh, -1)
+  return _maybe_lora(layer, "w_down", up, _linear(layer, "w_down", up, tp_mesh))
 
 
 def _moe_take(layer: Params, slot: str, idx: jnp.ndarray, eq: str, x: jnp.ndarray) -> jnp.ndarray:
@@ -496,12 +498,14 @@ def forward_shard(
   axis (see _moe_mlp).
 
   tp_mesh (static, hashable — same pattern as ring_mesh): the serving mesh
-  when this executable runs SPMD over a 'tp' axis. Activations get explicit
-  with_sharding_constraint pins at the Megatron column→row boundaries
-  (_tp_constraint) so GSPMD keeps heads/ffn columns sharded instead of
-  all-gathering; the paged Pallas kernels run per-tp-shard via shard_map
-  over the head-sliced arena (ops/paged_attention). Ignored on ring
-  (sequence-parallel) executables, whose activations shard over 'sp'.
+  when this executable runs SPMD over more than one device. Activations get
+  explicit with_sharding_constraint pins at the Megatron column→row
+  boundaries (_tp_constraint; a no-op without a tp axis wider than 1) so
+  GSPMD keeps heads/ffn columns sharded instead of all-gathering; every
+  attention Pallas kernel runs per device via shard_map over head-sliced
+  operands (parallel.mesh.per_shard_kernel), and the quantized matvec
+  kernels stand down (_linear). Ignored on ring (sequence-parallel)
+  executables, whose activations shard over 'sp'.
 
   cfg/is_first/is_last/use_flash/use_flash_decode must be static under jit;
   start_pos is traced so one executable serves every decode step. use_flash

@@ -173,7 +173,8 @@ def _flash_kernel_windowed(win_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, 
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_q", "block_k", "interpret", "softcap", "scale"))
+                   static_argnames=("block_q", "block_k", "interpret", "softcap", "scale",
+                                    "tp_mesh"))
 def flash_attention(
   q: jnp.ndarray,  # [B, T, Hq, D]
   k: jnp.ndarray,  # [B, T, Hkv, D]
@@ -184,6 +185,7 @@ def flash_attention(
   window: jnp.ndarray | None = None,  # traced scalar int32; None = global-only kernel
   softcap: float = 0.0,  # static tanh score cap (gemma2); 0 = off
   scale: float | None = None,  # static score scale; None = D**-0.5
+  tp_mesh=None,  # static Mesh: the kernel runs per device, heads sliced over 'tp'
 ) -> jnp.ndarray:
   """Causal grouped-query flash attention over one contiguous segment.
 
@@ -212,6 +214,18 @@ def flash_attention(
     raise ValueError(f"T={T} must be a multiple of block_q={block_q}, block_k={block_k}")
   if interpret is None:
     interpret = jax.default_backend() != "tpu"
+  if tp_mesh is not None:
+    # Under a serving mesh the Mosaic call must be manual on every device
+    # (parallel.mesh.per_shard_kernel); q/k/v arrive head-sharded from the
+    # projections' tp constraint, and attention never crosses heads.
+    from jax.sharding import PartitionSpec as P
+    from xotorch_tpu.parallel.mesh import head_axis, per_shard_kernel
+    heads = P(None, None, head_axis(tp_mesh, Hq, Hkv), None)
+    local = functools.partial(flash_attention, block_q=block_q, block_k=block_k,
+                              interpret=interpret, softcap=softcap, scale=scale)
+    return per_shard_kernel(
+      local, tp_mesh, (q, k, v), (heads, heads, heads), heads,
+      {"window": None if window is None else jnp.asarray(window, jnp.int32)}, {"window": P()})
 
   scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)
   # [B, H, T, D] layout: the kernel tiles the last two dims.

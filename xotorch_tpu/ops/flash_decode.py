@@ -44,19 +44,40 @@ from xotorch_tpu.ops.flash_attention import _mxu_operand, _softcap
 NEG_INF = -1e30
 
 
-def _load_kv(k_ref, v_ref, ks_ref, vs_ref, dt):
-  """Dequantize (or pass through) one kv tile pair. int8 caches carry one
-  scale per (position, head): the tile's [block_k] scale vector multiplies
-  in registers between the int8 DMA and the MXU dot, so HBM streams int8
-  bytes — the XLA fallback achieved the same fusion but read the ENTIRE
-  static buffer; here the occupancy/window DMA elision applies too.
-  Dequant runs in `dt` (the query's MXU dtype): identical math to the XLA
-  path's _cache_read, and the dot stays at full bf16 MXU rate."""
-  if ks_ref is None:
-    return _mxu_operand(k_ref[0, 0]), _mxu_operand(v_ref[0, 0])
-  k = k_ref[0, 0].astype(dt) * ks_ref[0, 0, 0].astype(dt)[:, None]
-  v = v_ref[0, 0].astype(dt) * vs_ref[0, 0, 0].astype(dt)[:, None]
-  return k, v
+def _kv_tiles(k_ref, v_ref, dt):
+  """One kv tile pair as MXU operands. An int8 cache's tiles convert to `dt`
+  (the query's MXU dtype) UNSCALED — small integers are exact in bf16 — and
+  `_scores` / `_weighted_values` apply the per-(position, head) scales on
+  the score side."""
+  if k_ref.dtype == jnp.int8:
+    return k_ref[0, 0].astype(dt), v_ref[0, 0].astype(dt)
+  return _mxu_operand(k_ref[0, 0]), _mxu_operand(v_ref[0, 0])
+
+
+def _scores(q, k, ks_ref, scale: float, softcap: float):
+  """[rows, block_k] f32 scores. int8 K dequantizes HERE: the scale of key
+  position j multiplies column j of q @ k_int8^T — the [1, block_k] scale
+  tile broadcasts along sublanes in its natural (lane-major) layout, where a
+  [block_k] -> [block_k, 1] column to scale K's rows is a relayout Mosaic
+  refuses (`infer-vector-layout: unsupported shape cast`, libtpu 0.0.34).
+  Same product as transformer._cache_read's k * scale, rounded once in f32
+  instead of once in bf16."""
+  s = jax.lax.dot_general(
+    q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+  if ks_ref is not None:
+    s = s * ks_ref[0, 0].astype(jnp.float32)
+  return _softcap(s * scale, softcap)
+
+
+def _weighted_values(p, v, vs_ref):
+  """p @ dequant(v): int8 V's per-position scale folds into the matching
+  COLUMN of p (p @ (v * s[:, None]) == (p * s[None, :]) @ v) — again a
+  lane-major [1, block_k] broadcast. The softmax denominator keeps the
+  unscaled p."""
+  if vs_ref is not None:
+    p = p * vs_ref[0, 0].astype(jnp.float32)
+  return jax.lax.dot_general(
+    p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
 
 def _cached_kernel(start_ref, *refs, block_q: int, block_k: int, groups: int, scale: float,
@@ -89,12 +110,8 @@ def _cached_kernel(start_ref, *refs, block_q: int, block_k: int, groups: int, sc
     # halve the MXU rate — this kernel also serves pos>0 chunked-prefill
     # segments, which are compute-bound).
     q = _mxu_operand(q_ref[0, 0])  # [block_q * groups, D]
-    k, v = _load_kv(k_ref, v_ref, ks_ref, vs_ref, q.dtype)
-
-    s = jax.lax.dot_general(
-      q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [block_q * groups, block_k]
-    s = _softcap(s, softcap)
+    k, v = _kv_tiles(k_ref, v_ref, q.dtype)
+    s = _scores(q, k, ks_ref, scale, softcap)  # [block_q * groups, block_k]
 
     # Row r is query position q_start + i*block_q + r // groups.
     row_pos = q_start + i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // groups
@@ -108,9 +125,7 @@ def _cached_kernel(start_ref, *refs, block_q: int, block_k: int, groups: int, sc
     p = jnp.exp(s - m_new)
 
     l_ref[:] = jnp.broadcast_to(alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-      p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    acc_ref[:] = acc_ref[:] * alpha + _weighted_values(p, v, vs_ref)
     m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
 
   @pl.when(j == n_k - 1)
@@ -160,12 +175,8 @@ def _cached_kernel_windowed(start_ref, win_ref, *refs, block_q: int, block_k: in
     # halve the MXU rate — this kernel also serves pos>0 chunked-prefill
     # segments, which are compute-bound).
     q = _mxu_operand(q_ref[0, 0])  # [block_q * groups, D]
-    k, v = _load_kv(k_ref, v_ref, ks_ref, vs_ref, q.dtype)
-
-    s = jax.lax.dot_general(
-      q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
-    s = _softcap(s, softcap)
+    k, v = _kv_tiles(k_ref, v_ref, q.dtype)
+    s = _scores(q, k, ks_ref, scale, softcap)
 
     row_pos = q_start + i * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // groups
     k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -180,9 +191,7 @@ def _cached_kernel_windowed(start_ref, win_ref, *refs, block_q: int, block_k: in
     p = jnp.exp(s - m_new)
 
     l_ref[:] = jnp.broadcast_to(alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-      p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    acc_ref[:] = acc_ref[:] * alpha + _weighted_values(p, v, vs_ref)
     m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
 
   @pl.when(j == n_k - 1)
@@ -193,7 +202,7 @@ def _cached_kernel_windowed(start_ref, win_ref, *refs, block_q: int, block_k: in
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "block_k", "interpret", "softcap",
-                                             "scale"))
+                                             "scale", "tp_mesh"))
 def flash_cached_attention(
   q: jnp.ndarray,  # [B, T, Hq, D] — queries at absolute positions q_start + [0, T)
   k: jnp.ndarray,  # [B, S, Hkv, D] — full static cache buffer (segment already written)
@@ -207,6 +216,7 @@ def flash_cached_attention(
   scale: float | None = None,  # static score scale; None = D**-0.5
   k_scale: jnp.ndarray | None = None,  # [B, S, Hkv] — int8 cache's per-(pos, head) scales
   v_scale: jnp.ndarray | None = None,
+  tp_mesh=None,  # static Mesh: the kernel runs per device, heads sliced over 'tp'
 ) -> jnp.ndarray:
   """Causal GQA attention of a query segment over the occupied cache prefix.
 
@@ -214,9 +224,10 @@ def flash_cached_attention(
   q_start + t] (window None/0 = the whole prefix). Returns [B, T, Hq, D].
   `window=None` (static) compiles the original kernel, so non-windowed
   families' executables are unchanged. With `k_scale`/`v_scale` the cache
-  buffers are raw int8 and dequantize IN-KERNEL per tile (models/
-  transformer._cache_read's math) — int8-KV long-context serving keeps both
-  the halved cache bandwidth and the occupancy/window DMA elision.
+  buffers are raw int8 and dequantize IN-KERNEL per tile (`_scores` /
+  `_weighted_values`: transformer._cache_read's product, scales applied on
+  the score side) — int8-KV long-context serving keeps both the halved
+  cache bandwidth and the occupancy/window DMA elision.
   """
   B, T, Hq, D = q.shape
   S, Hkv = k.shape[1], k.shape[2]
@@ -238,6 +249,22 @@ def flash_cached_attention(
     block_k //= 2
   if interpret is None:
     interpret = jax.default_backend() != "tpu"
+  if tp_mesh is not None:
+    # Under a serving mesh the Mosaic call must be manual on every device
+    # (parallel.mesh.per_shard_kernel). The cache is Hkv-sharded
+    # (parallel.mesh.cache_spec) and q head-sharded; positions and the
+    # window are replicated, and nothing crosses shards.
+    from jax.sharding import PartitionSpec as P
+    from xotorch_tpu.parallel.mesh import head_axis, per_shard_kernel
+    ax = head_axis(tp_mesh, Hq, Hkv)
+    heads, scales = P(None, None, ax, None), P(None, None, ax)
+    local = functools.partial(flash_cached_attention, block_q=block_q, block_k=block_k,
+                              interpret=interpret, softcap=softcap, scale=scale)
+    return per_shard_kernel(
+      local, tp_mesh, (q, k, v, q_start), (heads, heads, heads, P()), heads,
+      {"window": None if window is None else jnp.asarray(window, jnp.int32),
+       "k_scale": k_scale, "v_scale": v_scale},
+      {"window": P(), "k_scale": scales, "v_scale": scales})
 
   scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)
   # GQA packing: [B, Hkv, T * groups, D], row = position * groups + group.

@@ -26,6 +26,12 @@ One grid step per out-block with the FULL contraction in-kernel: a
 (out-block, group) grid measured 2.5x slower than XLA from sheer per-step
 overhead at matvec sizes. On CPU the kernel runs in interpret mode so
 tests exercise the same path.
+
+ONE kernel body: the scale-after-dot, int8-shift-unpack and W4A8 variants
+that were once selectable by environment variable all reshape the
+activation to a per-group batched operand in-kernel, which Mosaic
+(libtpu 0.0.34) refuses for a v5e — `infer-vector-layout: unsupported
+shape cast` — so they were deleted with their selector.
 """
 from __future__ import annotations
 
@@ -64,129 +70,18 @@ def _int4_matvec_kernel(he_ref, ho_ref, w_ref, gs_ref, o_ref):
   o_ref[...] = acc.astype(o_ref.dtype)
 
 
-def _int4_matvec_kernel_v2(he_ref, ho_ref, w_ref, gs_ref, o_ref):
-  """Scale-after-dot variant: contract RAW sign-extended nibbles (no
-  per-weight-element scale multiply — that was a full [in/2, block_out] VPU
-  pass per nibble half in v1), then apply the [G, out] group scales to the
-  [G, rows, out] per-group partials and reduce over G. The per-group
-  contraction runs as ONE batched MXU dot (G batch dims), so the extra
-  work is a tiny [G*rows*out] multiply-add instead of two [in/2 * out]
-  multiplies. Selected via XOT_INT4_V=2 for on-chip A/B measurement."""
-  packed = w_ref[...].astype(jnp.int32)  # [G, gs//2, block_out]
-  lo_f = _signext4(packed & 0xF).astype(jnp.float32)
-  hi_f = _signext4(packed >> 4).astype(jnp.float32)
-  G, gs_half, block_out = packed.shape
-  rows = he_ref.shape[0]
-
-  # [rows, G*gs_half] -> [G, rows, gs_half] batched lhs. The transpose is on
-  # the TINY activation (rows <= 8), not the weight tile.
-  he = he_ref[...].astype(jnp.float32).reshape(rows, G, gs_half).transpose(1, 0, 2)
-  ho = ho_ref[...].astype(jnp.float32).reshape(rows, G, gs_half).transpose(1, 0, 2)
-  # Batched over G: [G, rows, gs_half] x [G, gs_half, block_out] -> [G, rows, block_out]
-  dims = (((2,), (1,)), ((0,), (0,)))
-  part = jax.lax.dot_general(he, lo_f, dims, preferred_element_type=jnp.float32)
-  part = part + jax.lax.dot_general(ho, hi_f, dims, preferred_element_type=jnp.float32)
-  scale = gs_ref[...].astype(jnp.float32)  # [G, 1, block_out] broadcasts over rows
-  o_ref[...] = (part * scale).sum(axis=0).astype(o_ref.dtype)
-
-
-def _int4_matvec_kernel_v3(he_ref, ho_ref, w_ref, gs_ref, o_ref):
-  """int8-shift unpack + scale-after-dot: the v1/v2 unpack chain runs ~8
-  elementwise VPU passes over every packed element (i32 convert, mask,
-  shift, two-op sign extension each nibble, f32 converts, scales) — and the
-  VPU, not HBM, is what capped int4 decode at 26% of its roofline in round
-  3. Here the uint8 tile BITCASTS to int8 (modular astype) and the nibbles
-  sign-extend in pure int8 shift arithmetic:
-
-      lo = (p << 4) >> 4      (arithmetic shift sign-extends for free)
-      hi =  p >> 4
-
-  — three integer ops per packed element instead of seven, before the same
-  two f32 converts and the v2 batched-per-group MXU dot with the [G, out]
-  scales applied to the [G, rows, out] partials. Selected via XOT_INT4_V=3."""
-  packed8 = w_ref[...].astype(jnp.int8)  # modular: a bitcast of the uint8 tile
-  lo_f = ((packed8 << 4) >> 4).astype(jnp.float32)
-  hi_f = (packed8 >> 4).astype(jnp.float32)
-  G, gs_half, block_out = packed8.shape
-  rows = he_ref.shape[0]
-
-  he = he_ref[...].astype(jnp.float32).reshape(rows, G, gs_half).transpose(1, 0, 2)
-  ho = ho_ref[...].astype(jnp.float32).reshape(rows, G, gs_half).transpose(1, 0, 2)
-  dims = (((2,), (1,)), ((0,), (0,)))
-  part = jax.lax.dot_general(he, lo_f, dims, preferred_element_type=jnp.float32)
-  part = part + jax.lax.dot_general(ho, hi_f, dims, preferred_element_type=jnp.float32)
-  scale = gs_ref[...].astype(jnp.float32)  # [G, 1, block_out] broadcasts over rows
-  o_ref[...] = (part * scale).sum(axis=0).astype(o_ref.dtype)
-
-
-def _int4_matvec_kernel_v4(he_ref, ho_ref, hes_ref, hos_ref, w_ref, gs_ref, o_ref):
-  """W4A8: int8 x int8 MXU dot with int32 accumulation. v3 still pays two
-  full-tile f32 converts (one per nibble half) before the dot; here the
-  nibbles STAY int8 (the 3-op shift unpack) and the activations arrive
-  ALREADY row-quantized to int8 (done once outside the pallas_call — not
-  per out-block grid step), so the only per-weight-element work is the
-  unpack itself and the MXU consumes int8 at its doubled rate. Scales
-  compose after the dot: out = sum_G(part_i32 * a_scale[row] * gscale).
-
-  Activation quantization adds ~1/255 relative rounding per dot — an
-  APPROXIMATE variant (the weight-only v1-v3 are exact): selected only via
-  XOT_INT4_V=4, A/B'd on-chip like the others, oracle-tested to 1% rel L2
-  (the same budget the test asserts)."""
-  packed8 = w_ref[...].astype(jnp.int8)
-  lo8 = (packed8 << 4) >> 4
-  hi8 = packed8 >> 4
-  G, gs_half, block_out = packed8.shape
-  rows = he_ref.shape[0]
-  he = he_ref[...].reshape(rows, G, gs_half).transpose(1, 0, 2)  # [G, rows, gs_half]
-  ho = ho_ref[...].reshape(rows, G, gs_half).transpose(1, 0, 2)
-  dims = (((2,), (1,)), ((0,), (0,)))
-  pe = jax.lax.dot_general(he, lo8, dims, preferred_element_type=jnp.int32)
-  po = jax.lax.dot_general(ho, hi8, dims, preferred_element_type=jnp.int32)
-  scale = gs_ref[...].astype(jnp.float32)  # [G, 1, block_out]
-  part = (pe.astype(jnp.float32) * hes_ref[...][None]
-          + po.astype(jnp.float32) * hos_ref[...][None]) * scale
-  o_ref[...] = part.sum(axis=0).astype(o_ref.dtype)
-
-
-# v4 is NOT in this table: its operand list differs (int8 activations + two
-# scale inputs), so it dispatches through its own pallas_call branch below.
-_KERNELS = {1: _int4_matvec_kernel, 2: _int4_matvec_kernel_v2, 3: _int4_matvec_kernel_v3}
-
-
+@functools.partial(jax.jit, static_argnames=("block_out", "interpret"))
 def int4_grouped_matmul(
   h: jnp.ndarray,  # [rows, in] (rows small — decode)
   w_packed: jnp.ndarray,  # [G, gs // 2, out] uint8 (models/quantize.pack_int4)
   gscale: jnp.ndarray,  # [G, out]
   block_out: int = 1024,
   interpret: bool | None = None,
-  variant: int | None = None,  # 1 scale-into-operand, 2 scale-after-dot,
-  # 3 int8-shift unpack, 4 W4A8 int8-MXU (the only APPROXIMATE one:
-  # activations round to int8; v1-v3 are exact)
 ) -> jnp.ndarray:
   """h @ dequant(w) with the nibble unpack fused into the kernel.
 
-  Returns [rows, out] in h.dtype. `variant` (default env XOT_INT4_V, 1)
-  picks the kernel body for on-chip A/B measurement. The env is resolved
-  OUTSIDE the jitted impl so a direct caller always gets the current value;
-  when this runs inside an outer jit (the engine's decode executables) the
-  choice is baked at that outer trace — set XOT_INT4_V before first use.
-  """
-  if variant is None:
-    from xotorch_tpu.utils import knobs
-    variant = knobs.get_int("XOT_INT4_V")
-  return _int4_grouped_matmul_impl(h, w_packed, gscale, block_out=block_out,
-                                   interpret=interpret, variant=variant)
-
-
-@functools.partial(jax.jit, static_argnames=("block_out", "interpret", "variant"))
-def _int4_grouped_matmul_impl(
-  h: jnp.ndarray,
-  w_packed: jnp.ndarray,
-  gscale: jnp.ndarray,
-  block_out: int = 1024,
-  interpret: bool | None = None,
-  variant: int = 1,
-) -> jnp.ndarray:
+  Returns [rows, out] in h.dtype. Exact (weight-only quantization; f32
+  in-kernel math)."""
   rows, d_in = h.shape
   G, gs_half, d_out = w_packed.shape
   gs = gs_half * 2
@@ -195,12 +90,10 @@ def _int4_grouped_matmul_impl(
   block_out = min(block_out, d_out)
   while d_out % block_out:
     block_out //= 2
-  # VMEM bound: v1-v3 hold lo_f + hi_f at [d_in/2, block_out] f32 (8 bytes
-  # per packed element); v4's unpacked halves stay int8 (2 bytes). Cap the
-  # footprint at ~8 MB or the Mosaic compile blows VMEM on wide
-  # contractions (w_down: in=8192).
-  bytes_per_packed = 2 if variant == 4 else 8
-  while block_out > 128 and (d_in // 2) * block_out * bytes_per_packed > 8_000_000:
+  # VMEM bound: the kernel holds lo_f + hi_f at [d_in/2, block_out] f32 (8
+  # bytes per packed element). Cap the footprint at ~8 MB or the Mosaic
+  # compile blows VMEM on wide contractions (w_down: in=8192).
+  while block_out > 128 and (d_in // 2) * block_out * 8 > 8_000_000:
     block_out //= 2
   if interpret is None:
     interpret = jax.default_backend() != "tpu"
@@ -214,37 +107,15 @@ def _int4_grouped_matmul_impl(
   gs3 = gscale.reshape(G, 1, d_out)
 
   act_block = pl.BlockSpec((rows, G * gs_half), lambda j: (0, 0))
-  w_blocks = [
-    pl.BlockSpec((G, gs_half, block_out), lambda j: (0, 0, j)),
-    pl.BlockSpec((G, 1, block_out), lambda j: (0, 0, j)),
-  ]
-  if variant == 4:
-    # Row-quantize the activations ONCE here (not per out-block grid step):
-    # the kernel receives int8 halves + their [rows, 1] scales as operands.
-    # The recipe is shared with the W8A8 kernel (ops/int8_matmul.py).
-    from xotorch_tpu.ops.int8_matmul import rowquant_int8
-    he8, he_s = rowquant_int8(h_even)
-    ho8, ho_s = rowquant_int8(h_odd)
-    scale_block = pl.BlockSpec((rows, 1), lambda j: (0, 0))
-    out = pl.pallas_call(
-      _int4_matvec_kernel_v4,
-      grid=(d_out // block_out,),
-      in_specs=[act_block, act_block, scale_block, scale_block] + w_blocks,
-      out_specs=pl.BlockSpec((rows, block_out), lambda j: (0, j)),
-      out_shape=jax.ShapeDtypeStruct((rows, d_out), h.dtype),
-      interpret=interpret,
-    )(he8, ho8, he_s, ho_s, w_packed, gs3)
-    return out
-
-  # The kernel table is read at TRACE time, keyed by the static `variant`;
-  # retraces rebuild the same choice deterministically.
-  kernel = _KERNELS.get(variant, _int4_matvec_kernel)  # xotlint: disable=retrace-hazard (trace-time table)
-  out = pl.pallas_call(
-    kernel,
+  return pl.pallas_call(
+    _int4_matvec_kernel,
     grid=(d_out // block_out,),
-    in_specs=[act_block, act_block] + w_blocks,
+    in_specs=[
+      act_block, act_block,
+      pl.BlockSpec((G, gs_half, block_out), lambda j: (0, 0, j)),
+      pl.BlockSpec((G, 1, block_out), lambda j: (0, 0, j)),
+    ],
     out_specs=pl.BlockSpec((rows, block_out), lambda j: (0, j)),
     out_shape=jax.ShapeDtypeStruct((rows, d_out), h.dtype),
     interpret=interpret,
   )(h_even, h_odd, w_packed, gs3)
-  return out

@@ -14,8 +14,8 @@ drops to zero, and the scales compose after the dot:
 APPROXIMATE: activation rounding adds ~1/255 relative error per dot (the
 default fused-dequant path is exact in bf16). Opt-in via XOT_INT8_KERNEL=1
 (models/transformer._linear, decode-sized inputs on real TPU only), A/B'd
-on-chip like the int4 kernel variants. Same scope rules as int4: no GSPMD
-partitioning rule, so the engine disables it under a tp serving mesh.
+on-chip. Same scope rule as the int4 kernel: GSPMD cannot partition the
+custom call, so `_linear` takes the fused-dequant path under a serving mesh.
 
 No reference counterpart: the reference has no quantization at all
 (SURVEY §5 — torch fp32/fp16 end to end).
@@ -31,9 +31,7 @@ from jax.experimental import pallas as pl
 
 def rowquant_int8(a: jnp.ndarray):
   """Symmetric per-row int8 activation quantization: (int8 values,
-  [rows, 1] f32 scales). The ONE recipe both W*A8 kernels share (this
-  module and int4_matmul's v4) — divergent rounding between them would be
-  an invisible accuracy bug."""
+  [rows, 1] f32 scales)."""
   a = a.astype(jnp.float32)
   s = jnp.max(jnp.abs(a), axis=1, keepdims=True) / 127.0
   s = jnp.where(s == 0.0, 1.0, s)
@@ -41,7 +39,11 @@ def rowquant_int8(a: jnp.ndarray):
 
 
 def _int8_matvec_kernel(h8_ref, hs_ref, w_ref, ws_ref, o_ref):
+  # precision pinned: an int8 MXU dot has no higher-precision form, and under a
+  # process-wide jax_default_matmul_precision("highest") Mosaic refuses the
+  # inherited fp32 contract precision ("Bad lhs type", seen on a v5e).
   acc = jax.lax.dot_general(h8_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+                            precision=jax.lax.Precision.DEFAULT,
                             preferred_element_type=jnp.int32)  # [rows, block_out]
   o_ref[...] = (acc.astype(jnp.float32) * hs_ref[...].astype(jnp.float32)
                 * ws_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
@@ -59,14 +61,22 @@ def int8_rowquant_matmul(
   MXU. Returns [rows, out] in h.dtype."""
   rows, d_in = h.shape
   d_out = w.shape[1]
-  # Block choice: the largest DIVISOR of d_out within both the requested
-  # size and the VMEM cap (the int8 weight tile is d_in * block_out bytes;
-  # ~8 MB). Divisor-exact by construction — a halving loop can land on a
-  # non-divisor for odd-factored widths, silently under-covering the
-  # output grid. Trace-time only.
+  # Block choice: the whole output when it fits the request and the VMEM cap
+  # (the int8 weight tile is d_in * block_out bytes; ~8 MB), else the largest
+  # divisor of d_out that is a MULTIPLE OF 128 — the Pallas TPU lowering
+  # takes a block's lane dimension only as a multiple of 128 or the full
+  # axis ("largest divisor" alone landed on 2004 for the 128256-wide
+  # unembedding and was refused). Trace-time only.
   vmem_cap = max(128, 8_000_000 // max(d_in, 1))
-  target = max(1, min(block_out, d_out, vmem_cap))
-  block_out = max(d for d in range(1, target + 1) if d_out % d == 0)
+  target = min(block_out, vmem_cap)
+  if d_out <= target:
+    block_out = d_out
+  else:
+    lanes = [d for d in range(128, target + 1, 128) if d_out % d == 0]
+    if not lanes:
+      raise ValueError(f"int8_rowquant_matmul: out width {d_out} has no multiple-of-128 "
+                       f"divisor <= {target}; pad the projection or use the fused-dequant path")
+    block_out = lanes[-1]
   if interpret is None:
     interpret = jax.default_backend() != "tpu"
 

@@ -33,9 +33,9 @@ leave the original kernels byte-identical):
   window-expired pages back to the pool (vkv.py). Dead table slots hold the
   scratch page and sit below lo by construction.
 - `k_scale_pages`/`v_scale_pages` ([P, page, Hkv] per-layer SCALE pages,
-  int8-KV arenas): dequantized in-register between the int8 DMA and the MXU
-  dot, exactly `flash_decode._load_kv` — HBM streams int8 bytes, halving
-  paged KV bandwidth. A page id indexes payload and scale pages alike, so
+  int8-KV arenas): dequantized in-kernel on the score side, exactly
+  `flash_decode._scores` / `_weighted_values` — HBM streams int8 bytes,
+  halving paged KV bandwidth. A page id indexes payload and scale pages alike, so
   the same `_kv_map` serves both BlockSpecs.
 
 `paged_decode_attention` is T == 1 only (the decode step).
@@ -70,42 +70,37 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from xotorch_tpu.ops.flash_attention import _mxu_operand, _softcap
+from xotorch_tpu.ops.flash_attention import _mxu_operand
+from xotorch_tpu.ops.flash_decode import _kv_tiles, _scores, _weighted_values
 
 NEG_INF = -1e30
 
 
-def _tp_shards(tp_mesh, hq: int, hkv: int) -> int:
-  """tp width a paged kernel call can split over: >1 only when the mesh has
-  a 'tp' axis that divides BOTH head counts (GQA group size is then
-  preserved per shard). 1 means run the kernel unsharded."""
-  if tp_mesh is None or "tp" not in tp_mesh.axis_names:
-    return 1
-  tp = int(tp_mesh.shape["tp"])
-  return tp if tp > 1 and hq % tp == 0 and hkv % tp == 0 else 1
-
-
-def _tp_sharded_call(kernel, tp_mesh, operands, specs):
-  """Invoke a paged Pallas kernel PER TP SHARD via shard_map: q and the page
-  arena are sliced on their head axes ([B,T,Hq,D] / [P,page,Hkv,D], heads at
+def _run_paged_kernel(kernel, tp_mesh, q, k_pages, v_pages, page_table, rows,
+                      win, k_scale_pages, v_scale_pages):
+  """Call a paged Pallas kernel — once per device of the serving mesh when
+  there is one (parallel.mesh.per_shard_kernel: the chip's compiler refuses
+  an unwrapped Mosaic call inside a multi-device jit). q and the page arena
+  are sliced on their head axes ([B,T,Hq,D] / [P,page,Hkv,D], heads at
   index 2; scale pages [P,page,Hkv], heads at index 2 — matching
-  parallel.mesh.cache_spec), the table / row metadata / window replicated.
-  Each shard's kernel sees Hq/tp query heads over Hkv/tp arena heads — same
-  GQA group size, same grid shape, no cross-shard traffic (the softmax is
-  per head). This is how the kernels keep running under a tp serving mesh:
-  GSPMD has no partitioning rule for the custom call, so an unwrapped
-  kernel would make XLA all-gather the whole arena per step. The operand
-  list is VARIABLE (window / scale pages ride along when present), so the
-  caller supplies one spec per operand."""
-  from xotorch_tpu.parallel.mesh import shard_map
+  parallel.mesh.cache_spec) when 'tp' divides both head counts, so each
+  shard's kernel sees Hq/tp query heads over Hkv/tp arena heads: same GQA
+  group size, same grid shape, no cross-shard traffic (the softmax is per
+  head). The table / row metadata / window are replicated; window / scale
+  pages ride along when present."""
+  if tp_mesh is None:
+    return kernel(q, k_pages, v_pages, page_table, rows, win,
+                  k_scale_pages, v_scale_pages)
   from jax.sharding import PartitionSpec as P
-  heads = P(None, None, "tp", None)
-  per_shard = shard_map(
-    kernel, mesh=tp_mesh,
-    in_specs=tuple(specs),
-    out_specs=heads, check_rep=False,
-  )
-  return per_shard(*operands)
+  from xotorch_tpu.parallel.mesh import head_axis, per_shard_kernel
+  ax = head_axis(tp_mesh, q.shape[2], k_pages.shape[2])
+  heads, scales = P(None, None, ax, None), P(None, None, ax)
+  return per_shard_kernel(
+    lambda q_, kp, vp, pt, rows_, window=None, k_scale=None, v_scale=None:
+      kernel(q_, kp, vp, pt, rows_, window, k_scale, v_scale),
+    tp_mesh, (q, k_pages, v_pages, page_table, rows), (heads, heads, heads, P(), P()), heads,
+    {"window": win, "k_scale": k_scale_pages, "v_scale": v_scale_pages},
+    {"window": P(), "k_scale": scales, "v_scale": scales})
 
 
 def _logical_page_index(j, length, page_size: int, window=None):
@@ -166,18 +161,10 @@ def _paged_kernel(*refs, page: int, groups: int, scale: float, softcap: float,
   @pl.when(gate)
   def _compute():
     q = _mxu_operand(q_ref[0, 0])  # [groups, D]
-    if quant:
-      # flash_decode._load_kv: per-(position, head) scale multiplies in
-      # registers between the int8 DMA and the MXU dot.
-      k = k_ref[0, 0].astype(q.dtype) * ks_ref[0, 0, 0].astype(q.dtype)[:, None]
-      v = v_ref[0, 0].astype(q.dtype) * vs_ref[0, 0, 0].astype(q.dtype)[:, None]
-    else:
-      k = _mxu_operand(k_ref[0, 0])  # [page, D]
-      v = _mxu_operand(v_ref[0, 0])
-    s = jax.lax.dot_general(
-      q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [groups, page]
-    s = _softcap(s, softcap)
+    # int8 pages: the per-(position, head) scale tiles apply on the score
+    # side (flash_decode._scores / _weighted_values).
+    k, v = _kv_tiles(k_ref, v_ref, q.dtype)  # [page, D]
+    s = _scores(q, k, ks_ref, scale, softcap)  # [groups, page]
     # The decode query sits at position length - 1: every occupied position
     # is causally visible, so the mask is occupancy (plus the window).
     k_pos = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -194,9 +181,7 @@ def _paged_kernel(*refs, page: int, groups: int, scale: float, softcap: float,
     p = jnp.exp(s - m_new)
     l_ref[:] = jnp.broadcast_to(
       alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-      p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    acc_ref[:] = acc_ref[:] * alpha + _weighted_values(p, v, vs_ref)
     m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
 
   @pl.when(j == n_j - 1)
@@ -273,6 +258,10 @@ def _paged_attention_kernel(q, k_pages, v_pages, page_table, lengths,
   return out.reshape(B, 1, Hq, D)
 
 
+# Query rows (groups x positions) one ragged-kernel tile may hold in VMEM.
+_RAGGED_MAX_ROWS = 2048
+
+
 def _paged_ragged_kernel(*refs, page: int, groups: int, T: int, scale: float,
                          softcap: float, windowed: bool = False,
                          quant: bool = False):
@@ -318,16 +307,8 @@ def _paged_ragged_kernel(*refs, page: int, groups: int, T: int, scale: float,
   @pl.when(gate)
   def _compute():
     q = _mxu_operand(q_ref[0, 0])  # [groups*T, D]
-    if quant:
-      k = k_ref[0, 0].astype(q.dtype) * ks_ref[0, 0, 0].astype(q.dtype)[:, None]
-      v = v_ref[0, 0].astype(q.dtype) * vs_ref[0, 0, 0].astype(q.dtype)[:, None]
-    else:
-      k = _mxu_operand(k_ref[0, 0])  # [page, D]
-      v = _mxu_operand(v_ref[0, 0])
-    s = jax.lax.dot_general(
-      q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale  # [groups*T, page]
-    s = _softcap(s, softcap)
+    k, v = _kv_tiles(k_ref, v_ref, q.dtype)  # [page, D]
+    s = _scores(q, k, ks_ref, scale, softcap)  # [groups*T, page]
     # Row r is query offset t = r % T at absolute position q_start + t; it
     # attends key positions <= its own. Position 0 is visible to every row,
     # so m/l leave NEG_INF on the very first page — later fully-masked
@@ -351,9 +332,7 @@ def _paged_ragged_kernel(*refs, page: int, groups: int, T: int, scale: float,
     p = jnp.exp(s - m_new)
     l_ref[:] = jnp.broadcast_to(
       alpha * l_ref[:, :1] + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
-    acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-      p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    acc_ref[:] = acc_ref[:] * alpha + _weighted_values(p, v, vs_ref)
     m_ref[:] = jnp.broadcast_to(m_new, m_ref.shape)
 
   @pl.when(j == n_j - 1)
@@ -375,6 +354,23 @@ def _ragged_attention_kernel(q, k_pages, v_pages, page_table, kv_valid_len,
   B, T, Hq, D = q.shape
   P_, page, Hkv, _ = k_pages.shape
   groups = Hq // Hkv
+  # One tile holds every query row of a (batch, kv-head): q, the f32
+  # accumulator and the two lane-replicated softmax stats all scale with
+  # groups*T, and a 4096-position segment (the default XOT_PREFILL_CHUNK)
+  # overflows VMEM on a v5e (refused at compile time: "Ran out of memory in
+  # memory space vmem"). Longer segments run as consecutive position slices
+  # through the SAME kernel — slice i's queries end at kv position
+  # kv_valid_len - T + (i+1)*block_t, which is all the kernel's masks, gates
+  # and page clamps key off.
+  block_t = 1 << max((_RAGGED_MAX_ROWS // groups).bit_length() - 1, 0)
+  if T > block_t and T % block_t == 0:
+    outs = []
+    for i in range(T // block_t):
+      outs.append(_ragged_attention_kernel(
+        q[:, i * block_t:(i + 1) * block_t], k_pages, v_pages, page_table,
+        kv_valid_len - (T - (i + 1) * block_t), window, k_scale_pages,
+        v_scale_pages, scale=scale, softcap=softcap, interpret=interpret))
+    return jnp.concatenate(outs, axis=1)
   maxp = page_table.shape[1]
   windowed = window is not None
   quant = k_scale_pages is not None
@@ -473,19 +469,6 @@ def _paged_attention_xla(q, k_pages, v_pages, page_table, lengths,
                        scale=scale, softcap=softcap, window=window)
 
 
-def _paged_operand_specs(window, k_scale_pages):
-  """Per-operand PartitionSpecs for `_tp_sharded_call`, mirroring the
-  operand order (q, k_pages, v_pages, table, rows[, window][, scales])."""
-  from jax.sharding import PartitionSpec as P
-  heads = P(None, None, "tp", None)
-  specs = [heads, heads, heads, P(None, None), P(None)]
-  if window is not None:
-    specs.append(P(None))
-  if k_scale_pages is not None:
-    specs += [P(None, None, "tp"), P(None, None, "tp")]
-  return specs
-
-
 def paged_prefill_attention(
   q: jnp.ndarray,  # [B, T, Hq, D] — a prefill segment's queries (B == 1)
   k_pages: jnp.ndarray,  # [P, page, Hkv, D] — one layer's K arena
@@ -525,25 +508,8 @@ def paged_prefill_attention(
     k_scale = float(scale) if scale is not None else 1.0 / math.sqrt(D)
     kernel = functools.partial(_ragged_attention_kernel, scale=k_scale,
                                softcap=float(softcap), interpret=interpret)
-    operands = [q, k_pages, v_pages, page_table, kv_valid_len]
-    if win is not None:
-      operands.append(win)
-    if k_scale_pages is not None:
-      operands += [k_scale_pages, v_scale_pages]
-    if _tp_shards(tp_mesh, q.shape[2], k_pages.shape[2]) > 1:
-      def shard_kernel(q_, kp, vp, pt, rows, *extra):
-        i = 0
-        w = None
-        if win is not None:
-          w, i = extra[0], 1
-        ks = vs = None
-        if k_scale_pages is not None:
-          ks, vs = extra[i], extra[i + 1]
-        return kernel(q_, kp, vp, pt, rows, w, ks, vs)
-      return _tp_sharded_call(shard_kernel, tp_mesh, operands,
-                              _paged_operand_specs(win, k_scale_pages))
-    return kernel(q, k_pages, v_pages, page_table, kv_valid_len, win,
-                  k_scale_pages, v_scale_pages)
+    return _run_paged_kernel(kernel, tp_mesh, q, k_pages, v_pages, page_table,
+                             kv_valid_len, win, k_scale_pages, v_scale_pages)
   if use_kernel:
     # Legacy gathered view: int8 arenas hand the RAW pages + gathered
     # scales to flash_cached, which dequantizes in-kernel over the view.
@@ -559,7 +525,8 @@ def paged_prefill_attention(
     q_start = kv_valid_len.astype(jnp.int32) - T
     return flash_cached_attention(q, k, v, q_start, window=window,
                                   softcap=softcap, scale=scale,
-                                  k_scale=ks, v_scale=vs, interpret=interpret)
+                                  k_scale=ks, v_scale=vs, interpret=interpret,
+                                  tp_mesh=tp_mesh)
   from xotorch_tpu.ops.attention import gqa_attention
   k, v = _gather_paged_view(q, k_pages, v_pages, page_table,
                             k_scale_pages, v_scale_pages)
@@ -599,25 +566,8 @@ def paged_decode_attention(
     win = None if window is None else jnp.asarray(window, jnp.int32).reshape(1)
     kernel = functools.partial(_paged_attention_kernel, scale=scale,
                                softcap=float(softcap), interpret=interpret)
-    operands = [q, k_pages, v_pages, page_table, lengths]
-    if win is not None:
-      operands.append(win)
-    if k_scale_pages is not None:
-      operands += [k_scale_pages, v_scale_pages]
-    if _tp_shards(tp_mesh, q.shape[2], k_pages.shape[2]) > 1:
-      def shard_kernel(q_, kp, vp, pt, rows, *extra):
-        i = 0
-        w = None
-        if win is not None:
-          w, i = extra[0], 1
-        ks = vs = None
-        if k_scale_pages is not None:
-          ks, vs = extra[i], extra[i + 1]
-        return kernel(q_, kp, vp, pt, rows, w, ks, vs)
-      return _tp_sharded_call(shard_kernel, tp_mesh, operands,
-                              _paged_operand_specs(win, k_scale_pages))
-    return kernel(q, k_pages, v_pages, page_table, lengths, win,
-                  k_scale_pages, v_scale_pages)
+    return _run_paged_kernel(kernel, tp_mesh, q, k_pages, v_pages, page_table,
+                             lengths, win, k_scale_pages, v_scale_pages)
   return _paged_attention_xla(q, k_pages, v_pages, page_table, lengths,
                               scale, float(softcap), window=window,
                               k_scale_pages=k_scale_pages,

@@ -124,9 +124,7 @@ def ring_attention_sharded(
   b_ax = "dp" if "dp" in names else None
   h_ax = "tp" if "tp" in names else None
   spec = P(b_ax, axis_name, h_ax, None)
-  from xotorch_tpu.parallel.mesh import shard_map
-
-  fn = shard_map(
+  fn = jax.shard_map(
     functools.partial(ring_attention, axis_name=axis_name),
     mesh=mesh,
     in_specs=(spec, spec, spec),

@@ -30,28 +30,41 @@ def _int4_dense_slots():
   return _INT4_LAYER_SLOTS
 
 
-def shard_map(f, mesh, in_specs, out_specs, **kwargs):
-  """Version-portable shard_map: `jax.shard_map` when the alias exists
-  (newer JAX), else `jax.experimental.shard_map.shard_map`. The
-  replication-check kwarg was renamed across versions (`check_rep` →
-  `check_vma`); either spelling is accepted here and forwarded under
-  whichever name the resolved implementation takes (dropped if neither)."""
-  import inspect
+def head_axis(mesh, *head_counts: int) -> Optional[str]:
+  """The mesh axis a kernel's HEAD dimension splits over: 'tp' when the mesh
+  has a tp axis wider than 1 that divides every given head count (GQA group
+  size is then preserved per shard), else None (heads replicated)."""
+  if mesh is None or "tp" not in mesh.axis_names:
+    return None
+  tp = int(mesh.shape["tp"])
+  return "tp" if tp > 1 and all(h % tp == 0 for h in head_counts) else None
 
+
+def per_shard_kernel(kernel, mesh, operands, specs, out_spec, optional=None,
+                     optional_specs=None):
+  """Run a Pallas kernel once PER DEVICE of the serving mesh:
+  `kernel(*operands, **present_optionals)` with each operand sliced per its
+  PartitionSpec. `optional` maps keyword -> operand-or-None (a window, int8
+  scale tiles): the None ones are dropped together with their
+  `optional_specs`, so one call site serves every variant of a kernel.
+
+  A Mosaic custom call has no partitioning rule: inside a jit that spans
+  more than one device the TPU lowering refuses it outright ("Mosaic kernels
+  cannot be automatically partitioned. Please wrap the call in a
+  shard_map") — on the virtual CPU mesh the kernels run interpreted as
+  plain XLA ops, so only the chip's compiler ever said so. `jax.shard_map`
+  over EVERY mesh axis makes the call manual: operands whose spec names
+  'tp' arrive sliced on that axis (heads — attention is per head, so no
+  cross-shard traffic), everything else replicated; axes the specs never
+  name (sp/ep) just see replicated operands. check_vma is off: the kernel
+  body is opaque to the replication checker."""
   import jax
-
-  impl = getattr(jax, "shard_map", None)
-  if impl is None:
-    from jax.experimental.shard_map import shard_map as impl
-  accepted = inspect.signature(impl).parameters
-  check = kwargs.pop("check_vma", kwargs.pop("check_rep", None))
-  if check is not None:
-    for alias in ("check_vma", "check_rep"):
-      if alias in accepted:
-        kwargs[alias] = check
-        break
-  kwargs = {k: v for k, v in kwargs.items() if k in accepted}
-  return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+  present = {n: a for n, a in (optional or {}).items() if a is not None}
+  per_shard = jax.shard_map(
+    lambda ops, opt: kernel(*ops, **opt), mesh=mesh,
+    in_specs=(tuple(specs), {n: optional_specs[n] for n in present}),
+    out_specs=out_spec, check_vma=False)
+  return per_shard(tuple(operands), present)
 
 
 def make_mesh(axis_sizes: Dict[str, int], devices: Optional[Sequence] = None):

@@ -51,7 +51,6 @@ _DEFS: Tuple[Knob, ...] = (
   Knob("XOT_MAX_RESIDENT_REQUESTS", "int", "8", "Max request states resident per shard context before LRU eviction.", "Engine"),
   Knob("XOT_MAX_RESIDENT_MODELS", "int", "2", "Max model shard contexts resident before LRU eviction of whole models.", "Engine"),
   Knob("XOT_PREFILL_CHUNK", "int", "4096", "Prefill chunk length (tokens): prompts longer than this prefill in chunks.", "Engine"),
-  Knob("XOT_COMPILE_CACHE_DIR", "path", None, "Persistent JAX compilation cache directory: a respawned replica's first request loads executables from disk instead of paying the cold-jit stall; unset leaves the JAX default.", "Engine"),
   Knob("XOT_SCAN_PREFILL", "bool", "1", "Use the lax.scan prefill over equal chunks (one compile for any chunk count).", "Engine"),
   Knob("XOT_DECODE_BATCH", "int", "8", "Max concurrent requests fused into one batched decode dispatch.", "Engine"),
   Knob("XOT_BATCH_WINDOW_MS", "float", "0", "Batching window (ms) the decode batcher waits to coalesce submitters; 0 = one event-loop tick.", "Engine"),
@@ -84,7 +83,6 @@ _DEFS: Tuple[Knob, ...] = (
   Knob("XOT_FD_BLOCK_Q", "int", "128", "Flash-decode query-head block size.", "Kernels"),
   Knob("XOT_FD_BLOCK_K", "int", "256", "Flash-decode key/value block size.", "Kernels"),
   Knob("XOT_INT4_KERNEL", "str", "1", "Fused int4 matmul kernel: `1` on real TPU, `0` off, `force` even off-TPU.", "Kernels"),
-  Knob("XOT_INT4_V", "int", "1", "Int4 kernel variant selector (1 or 2).", "Kernels"),
   Knob("XOT_INT8_KERNEL", "str", "0", "Fused int8 matmul kernel: `1` on real TPU, `0` off, `force` even off-TPU.", "Kernels"),
   # ----------------------------------------------------------- speculative
   Knob("XOT_SPECULATE", "int", "0", "Speculative draft depth (tokens per round); 0 disables (8 implied by XOT_DRAFT_MODEL).", "Speculative"),
@@ -153,7 +151,6 @@ _DEFS: Tuple[Knob, ...] = (
   Knob("XOT_PROCESS_ID", "int", None, "This process's index for JAX multi-host init (required with XOT_COORDINATOR).", "Topology"),
   Knob("XOT_PROBE_TIMEOUT", "float", "120", "Timeout (s) for the device-capability accelerator probe subprocess.", "Topology"),
   Knob("XOT_SKIP_JAX_PROBE", "bool", "0", "Skip the JAX accelerator probe (report CPU-only capabilities).", "Topology"),
-  Knob("XOT_PLATFORM", "str", None, "Force the JAX platform (`cpu`/`tpu`/`gpu`) before first device touch.", "Topology"),
   # ------------------------------------------------------ paths / identity
   Knob("XOT_HOME", "path", None, "Root directory for downloads and state; unset uses `~/.xot_tpu`.", "Paths"),
   Knob("XOT_MODEL_DIR", "path", None, "Local directory of model checkpoints (offline serving).", "Paths"),
